@@ -58,6 +58,7 @@ from repro.compress import make_compressor
 from repro.configs.registry import get_arch
 from repro.core.engine import run_rounds
 from repro.core.simulate import make_sim_step
+from repro.launch import compile_cache
 from repro.core.types import FLConfig
 from repro.data.synthetic import FedDataConfig, eval_batch, sample_round
 from repro.models.model import Model
@@ -767,7 +768,6 @@ def bench_fused(rounds):
     """
     import re
     from repro.compress.wire_format import payload_nbytes
-    from repro.core.compat import make_mesh
     from repro.core.federated import make_fl_train_step
     from repro.launch import hlo_analysis
 
@@ -811,7 +811,8 @@ def bench_fused(rounds):
         return
     # model axis of size 1: every all-gather in the program is the client
     # aggregation wire, so total-AG comparisons are pure payload
-    mesh = make_mesh((8, 1), ("data", "model"))
+    mesh = jax.make_mesh((8, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     def ag_bytes_by_dtype(hlo_text):
         """Sum all-gather result bytes per dtype (variadic AGs included)."""
@@ -1416,6 +1417,7 @@ def main() -> None:
                     help="also write the emitted rows + git SHA / config "
                          "hash / backend as a per-PR JSON record")
     args = ap.parse_args()
+    compile_cache.enable()
     SMOKE = args.smoke
     only = None
     if args.only:

@@ -28,8 +28,8 @@ def main():
     ap.add_argument("--rounds", type=int, default=12)
     args = ap.parse_args()
 
-    from repro.core.compat import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     cfg = ArchConfig(name="hier-demo", family="dense", num_layers=2,
                      d_model=128, num_heads=4, num_kv_heads=2, d_ff=256,
                      vocab_size=256, block_pattern=("attn+mlp",),

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.compress.api import CommTransform
 from repro.compress.secure_agg import MASK_TAG, has_mask_ctx, inject_mask_ctx
 
@@ -167,12 +166,12 @@ def make_aggregator(mesh: Mesh, param_specs: PyTree, pipe: CommTransform,
         # shard_map can't take None pytrees for the state slot when the
         # pipeline is stateless; close over it instead.
         if stateful:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda d, w, r, s: body(d, w, r, s),
                 mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False)
             return fn(deltas, weights, rng, comm_state)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda d, w, r: body(d, w, r, None)[0],
             mesh=mesh, in_specs=in_specs[:3], out_specs=out_specs[0],
             check_vma=False)

@@ -50,7 +50,6 @@ from repro.compress.secure_agg import (DPNoise, MASK_TAG, SecAgg,
 from repro.core import aggregation, selection as sel, server_opt
 from repro.core import scenario as scn_mod
 from repro.core.aggregation import comm_state_init, comm_state_specs
-from repro.core.compat import shard_map
 from repro.core.types import CommLedger, FLConfig, FLState
 from repro.data.pipeline import capability_latency
 from repro.models import sharding as shd
@@ -1280,13 +1279,13 @@ def _build_hier(model: Model, fl: FLConfig, topo: Topology, mesh: Mesh,
             return agg, (tuple(st_out) if stateful else ())
 
         if stateful:
-            return shard_map(body, mesh=mesh,
-                             in_specs=(dspecs, P(), comm_specs),
-                             out_specs=(gspecs, comm_specs),
-                             check_vma=False)(deltas, weights, comm_state)
-        agg = shard_map(lambda d, w: body(d, w, None)[0], mesh=mesh,
-                        in_specs=(dspecs, P()),
-                        out_specs=gspecs, check_vma=False)(deltas, weights)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(dspecs, P(), comm_specs),
+                                 out_specs=(gspecs, comm_specs),
+                                 check_vma=False)(deltas, weights, comm_state)
+        agg = jax.shard_map(lambda d, w: body(d, w, None)[0], mesh=mesh,
+                            in_specs=(dspecs, P()),
+                            out_specs=gspecs, check_vma=False)(deltas, weights)
         return agg, None
 
     def _sync_models(params, rng):
@@ -1311,8 +1310,8 @@ def _build_hier(model: Model, fl: FLConfig, topo: Topology, mesh: Mesh,
                 out.append(synced.reshape(leaf.shape).astype(leaf.dtype))
             return jax.tree.unflatten(jax.tree.structure(ptree), out)
 
-        return shard_map(body, mesh=mesh, in_specs=(gspecs,),
-                         out_specs=gspecs, check_vma=False)(params)
+        return jax.shard_map(body, mesh=mesh, in_specs=(gspecs,),
+                             out_specs=gspecs, check_vma=False)(params)
 
     def _pod_divergence(params):
         """Mean squared distance of per-pod models from their mean — the
@@ -1555,13 +1554,13 @@ def _build_gossip(model: Model, fl: FLConfig, topo: Topology, mesh: Mesh,
             return tree, (tuple(st_out) if stateful else ())
 
         if stateful:
-            return shard_map(body, mesh=mesh,
-                             in_specs=(cspecs, comm_specs),
-                             out_specs=(cspecs, comm_specs),
-                             check_vma=False)(params, comm_state)
-        mixed = shard_map(lambda p: body(p, None)[0], mesh=mesh,
-                          in_specs=(cspecs,),
-                          out_specs=cspecs, check_vma=False)(params)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(cspecs, comm_specs),
+                                 out_specs=(cspecs, comm_specs),
+                                 check_vma=False)(params, comm_state)
+        mixed = jax.shard_map(lambda p: body(p, None)[0], mesh=mesh,
+                              in_specs=(cspecs,),
+                              out_specs=cspecs, check_vma=False)(params)
         return mixed, None
 
     def hop_rng(ctx):
@@ -1845,10 +1844,7 @@ class RoundRunner:
 
     def cache_size(self):
         """Number of distinct compilations so far (one per chunk shape)."""
-        try:
-            return self._jit._cache_size()
-        except AttributeError:      # pragma: no cover — very old/new jax
-            return None
+        return self._jit._cache_size()
 
     def run(self, state, n: int):
         """Run ``n`` rounds; returns (state, metrics) with every metric (and
@@ -1884,8 +1880,7 @@ class RoundRunner:
                 with self.tracer.span("chunk", rounds=k) as sp:
                     state, m = self._jit(state, k)
                     jax.block_until_ready(m)
-                    if before is not None and \
-                            (self.cache_size() or 0) > before:
+                    if self.cache_size() > before:
                         sp["kind"] = "compile"
             chunks.append(m)
             done += k

@@ -1,8 +1,11 @@
 """Public jit'd wrappers over the Pallas compression kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs through JAX's interpreter, proving the Pallas logic without
-TPU hardware. On a real TPU backend the same calls lower to Mosaic.
+On the CPU platform (the test path) the kernels execute with
+``interpret=True`` — the kernel body runs through JAX's interpreter, proving
+the Pallas logic without TPU hardware. On TPU the same calls always lower to
+Mosaic, and any other platform is an error: there is no silent fallback to
+the interpreter (``tests/test_tpu_compile.py`` compiles every kernel for a
+described v5e).
 
 Each wrapper handles the flat-vector <-> blocked layout plumbing so callers
 (the compressors in ``repro.compress``) see the same flat-f32 interface as
@@ -28,7 +31,12 @@ ROWS = _qsgd.ROWS
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on 'tpu' (Mosaic) or interpreted on 'cpu'; "
+            f"the default backend is {platform!r}")
+    return platform == "cpu"
 
 
 def _to_blocked(x, block):
@@ -183,11 +191,5 @@ def threshold_sparsify(x, thresh, block=2048):
 def sketch(x, rows=5, cols=4096, seed=17):
     """Count-sketch via the one-hot-MXU kernel. Flat f32 (n,) -> (rows, cols)."""
     from repro.compress.sketch import hash_params
-    n = x.shape[0]
-    pad = (-n) % _cs.CHUNK
-    xp = jnp.pad(x.astype(jnp.float32), (0, pad))
     a, b = hash_params(rows, seed)
-    S = _cs.count_sketch(xp, a, b, rows, cols, interpret=_interpret())
-    # padded elements are zero-valued, so their bucket contributions are
-    # zero and S is already exact.
-    return S
+    return _cs.count_sketch(x, a, b, rows, cols, interpret=_interpret())

@@ -10,6 +10,10 @@ the block matrix in VMEM. ``block`` must be a multiple of 128 (lane width);
 ROWS=8 keeps the tile at 8×block×4 B (e.g. 64 KiB for block=2048) — well
 inside VMEM. Stochastic-rounding uniforms are an *input* (generated with
 jax.random outside) so the kernel is bit-reproducible against ``ref.py``.
+
+The per-row scale leaves the kernel as an (nb, 1) column and is reshaped to
+(nb,) outside: Mosaic refuses a rank-1 ``(ROWS,)`` block (a rank-1 block must
+span the array or be a multiple of 128 lanes).
 """
 from __future__ import annotations
 
@@ -19,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS = 8
+from repro.kernels.layout import ROWS
 
 
 def _kernel(x_ref, u_ref, q_ref, scale_ref, *, levels: int):
@@ -28,7 +32,7 @@ def _kernel(x_ref, u_ref, q_ref, scale_ref, *, levels: int):
     y = x / jnp.maximum(scale, 1e-30) * levels
     q = jnp.floor(y + u_ref[...])
     q_ref[...] = q.astype(jnp.int8)
-    scale_ref[...] = scale[:, 0]
+    scale_ref[...] = scale
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -38,7 +42,7 @@ def qsgd_quantize_blocked(xb, u, bits=8, interpret=False):
     assert nb % ROWS == 0, (nb, ROWS)
     levels = 2 ** (bits - 1) - 1
     grid = (nb // ROWS,)
-    return pl.pallas_call(
+    q, scale = pl.pallas_call(
         functools.partial(_kernel, levels=levels),
         grid=grid,
         in_specs=[
@@ -47,11 +51,12 @@ def qsgd_quantize_blocked(xb, u, bits=8, interpret=False):
         ],
         out_specs=[
             pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS,), lambda i: (i,)),
+            pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, block), jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xb, u)
+    return q, scale.reshape(nb)

@@ -16,12 +16,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS = 8
+from repro.kernels.layout import ROWS, SCALAR
 
 
 def _kernel(x_ref, t_ref, kept_ref, resid_ref):
     x = x_ref[...]
-    keep = jnp.abs(x) >= t_ref[0]
+    keep = jnp.abs(x) >= t_ref[0, 0]
     kept = jnp.where(keep, x, 0.0)
     kept_ref[...] = kept
     resid_ref[...] = x - kept
@@ -32,13 +32,13 @@ def threshold_sparsify_blocked(xb, thresh, interpret=False):
     """xb (nb, block) f32 -> (kept, resid) same shape."""
     nb, block = xb.shape
     assert nb % ROWS == 0
-    t = jnp.reshape(thresh.astype(jnp.float32), (1,))
+    t = jnp.reshape(thresh.astype(jnp.float32), (1, 1))
     return pl.pallas_call(
         _kernel,
         grid=(nb // ROWS,),
         in_specs=[
             pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            SCALAR,
         ],
         out_specs=[
             pl.BlockSpec((ROWS, block), lambda i: (i, 0)),

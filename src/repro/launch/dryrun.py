@@ -32,7 +32,7 @@ from repro.core.types import FLConfig
 from repro.core.federated import make_fl_train_step
 from repro.core.hierarchical import make_hier_fl_train_step
 from repro.launch import hlo_analysis as hlo
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.models import sharding as shd
 from repro.models.model import Model, set_activation_mesh
 
@@ -309,7 +309,8 @@ def run_one(arch: str, shape_name: str, mesh_name: str, fl_name: str,
         hbm_est = ca.get("bytes accessed", 0.0) * corr
         stats_est = dataclasses.replace(stats, hbm_bytes=hbm_est) \
             if hbm_est else stats
-        terms = hlo.roofline(stats_est)
+        # the placeholder CPU devices stand in for the production chips
+        terms = hlo.roofline(stats_est, PRODUCTION_DEVICE_KIND)
         model = Model(cfg)
         mf = model_flops(model, shape_cfg) / n_dev
         total, active = active_params(model)

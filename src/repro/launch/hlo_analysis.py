@@ -323,10 +323,24 @@ def name_stage_mismatch(stage_names, stage_table, measured: float,
 
 # ------------------------------------------------------------------ roofline
 
-V5E = {"flops_bf16": 197e12, "hbm_gbps": 819e9, "ici_gbps": 50e9}
+# Published per-chip peaks, keyed by jax's ``device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s of inter-chip interconnect (50 GB/s per link of four).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_gbps": 819e9,
+                    "ici_gbps": 50e9},
+}
 
 
-def roofline(stats: HLOStats, hw=V5E) -> dict:
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; have {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
+def roofline(stats: HLOStats, device_kind: str) -> dict:
+    hw = peaks(device_kind)
     return {
         "compute_s": stats.flops / hw["flops_bf16"],
         "memory_s": stats.hbm_bytes / hw["hbm_gbps"],

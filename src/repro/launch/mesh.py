@@ -7,8 +7,11 @@ never touches jax device state — required for the dry-run's
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.core.compat import make_mesh
+
+# jax's device_kind of the production chip the meshes below model
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +22,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, data: int | None = None, pod: int = 1):
@@ -29,4 +33,4 @@ def make_host_mesh(model: int = 1, data: int | None = None, pod: int = 1):
         data = n // (model * pod)
     shape = (pod, data, model) if pod > 1 else (data, model)
     axes = ("pod", "data", "model") if pod > 1 else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -20,6 +20,8 @@ def main():
     ap.add_argument("--window", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     from repro import checkpoint

@@ -9,6 +9,11 @@ path pays one dispatch per chunk instead of per round (``--chunk 1`` falls
 back to per-round stepping for debugging). On real TPU hardware omit
 --devices (uses the actual topology). On CPU, --devices N simulates an
 N-device host for the mesh (set before jax init).
+
+The mesh path puts one client on each ``data`` slice of ``--model-parallel``
+devices (default 2): one chip gives one client, four chips give two. For a
+real cohort on one chip use the mesh-free ``--population C --cohort C``
+path (``Topology.sim``).
 """
 import argparse
 import os
@@ -124,6 +129,8 @@ def main():
             "XLA_FLAGS",
             f"--xla_force_host_platform_device_count={args.devices}")
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     from repro import checkpoint
@@ -282,6 +289,11 @@ def main():
 
     n = jax.device_count()
     mp = min(args.model_parallel, n)
+    if args.hierarchical and n < 2 * mp:
+        raise SystemExit(
+            f"--hierarchical needs a pod=2 x data x model={mp} mesh, i.e. at "
+            f"least {2 * mp} devices; JAX sees {n}. On one chip use "
+            f"--population (Topology.sim), or lower --model-parallel")
     if args.hierarchical:
         mesh = make_host_mesh(model=mp, pod=2, data=n // (2 * mp))
     else:
@@ -356,7 +368,7 @@ def main():
                     start_round=done)
             done += k
     if args.checkpoint:
-        _save_checkpoint(global_params(state))
+        _save_checkpoint(global_params(state.params))
     if tracer is not None:
         tracer.close()
         print(f"trace: {args.trace} (render: python -m repro.obs.report "

@@ -10,7 +10,7 @@ import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
 import numpy as np                                              # noqa: E402
 
-from repro.core.compat import make_mesh                        # noqa: E402
+from jax.sharding import AxisType                               # noqa: E402
 from repro.core.types import ArchConfig, FLConfig               # noqa: E402
 from repro.core.federated import make_fl_train_step             # noqa: E402
 from repro.core.hierarchical import make_hier_fl_train_step     # noqa: E402
@@ -28,11 +28,13 @@ def tiny_cfg(**kw):
 
 
 def mesh3():
-    return make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
 
 
 def mesh2():
-    return make_mesh((4, 2), ("data", "model"))
+    return jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_batch(cfg, C, B, S, key):

@@ -167,9 +167,11 @@ def test_shape_bytes():
 def test_roofline_dominant():
     from repro.launch.hlo_analysis import HLOStats
     st = HLOStats(flops=197e12, hbm_bytes=819e9 * 3, coll_bytes=50e9 * 2)
-    terms = roofline(st)
+    terms = roofline(st, "TPU v5 lite")
     assert terms["compute_s"] == pytest.approx(1.0)
     assert dominant(terms) == "memory"
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline(st, "cpu")
 
 
 def test_reduced_configs_are_small():
