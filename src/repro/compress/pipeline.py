@@ -20,6 +20,7 @@ working vector internally, so they compose with any inner pipeline.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Tuple
 
 import jax
@@ -27,10 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compress.api import CommTransform, Identity
+from repro.obs import scopes
 
 __all__ = ["Chain", "chain", "ErrorFeedback", "error_feedback",
            "MomentumCorrection", "momentum_correction",
-           "stage_sequence", "stage_input_lens"]
+           "stage_sequence", "stage_input_lens", "scoped_encode",
+           "scoped_decode"]
 
 
 class Chain(CommTransform):
@@ -75,7 +78,8 @@ class Chain(CommTransform):
         payload, new_states, cur = {}, [], x
         last = len(self.stages) - 1
         for i, s in enumerate(self.stages):
-            p, st = s.encode(state[i], jax.random.fold_in(rng, i), cur)
+            p, st = scoped_encode(s, state[i], jax.random.fold_in(rng, i),
+                                  cur)
             new_states.append(st)
             if i < last:
                 p = dict(p)
@@ -86,11 +90,11 @@ class Chain(CommTransform):
     def decode(self, payload, n):
         ms = self._lens(n)
         last = len(self.stages) - 1
-        cur = self.stages[last].decode(payload[f"s{last}"], ms[last])
+        cur = scoped_decode(self.stages[last], payload[f"s{last}"], ms[last])
         for i in range(last - 1, -1, -1):
             p = dict(payload[f"s{i}"])
             p[self.stages[i].carrier_key] = cur
-            cur = self.stages[i].decode(p, ms[i])
+            cur = scoped_decode(self.stages[i], p, ms[i])
         return cur
 
     # --- byte accounting ----------------------------------------------------
@@ -113,6 +117,27 @@ class Chain(CommTransform):
             total += s.meta_entropy_bits_given(m, hint)
             hint = s.carrier_hint(m)
         return total
+
+
+def _stage_scope(t: CommTransform):
+    """The ``stage.<base>`` scope (``repro.obs.scopes``) of a carrier
+    stage's work; chains and wrappers (anything with an ``inner``) name
+    their own parts, and the identity does no work."""
+    if isinstance(t, Chain) or hasattr(t, "inner") or t.is_identity:
+        return contextlib.nullcontext()
+    return jax.named_scope(scopes.stage(t.name))
+
+
+def scoped_encode(t: CommTransform, state, rng, x):
+    """``t.encode`` under ``t``'s stage scope (HLO metadata only)."""
+    with _stage_scope(t):
+        return t.encode(state, rng, x)
+
+
+def scoped_decode(t: CommTransform, payload, n):
+    """``t.decode`` under ``t``'s stage scope (HLO metadata only)."""
+    with _stage_scope(t):
+        return t.decode(payload, n)
 
 
 def stage_sequence(pipe: CommTransform) -> Tuple[CommTransform, ...]:
@@ -169,7 +194,7 @@ class _Wrapper(CommTransform):
         self.inner = inner
 
     def decode(self, payload, n):
-        return self.inner.decode(payload, n)
+        return scoped_decode(self.inner, payload, n)
 
     def meta_bits(self, n):
         return self.inner.wire_bits(n)
@@ -195,13 +220,15 @@ class ErrorFeedback(_Wrapper):
                 "inner": self.inner.init(shape)}
 
     def encode(self, state, rng, x):
-        y = x + self.decay * state["residual"].reshape(x.shape)
-        payload, ist = self.inner.encode(state["inner"], rng, y)
-        # local decode of our own payload: one extra O(n) dequantize per leaf
-        # vs. an aggregator that reuses its post-gather decode — the price of
-        # keeping correction state out of the aggregation layer entirely
-        y_hat = self.inner.decode(payload, y.shape[0])
-        res = (y - y_hat).reshape(state["residual"].shape)
+        # stage.ef names the residual arithmetic; the inner stages nest
+        with jax.named_scope(scopes.STAGE + "ef"):
+            y = x + self.decay * state["residual"].reshape(x.shape)
+            payload, ist = scoped_encode(self.inner, state["inner"], rng, y)
+            # local decode of our own payload: one extra O(n) dequantize per
+            # leaf vs. an aggregator that reuses its post-gather decode — the
+            # price of keeping correction state out of the aggregation layer
+            y_hat = scoped_decode(self.inner, payload, y.shape[0])
+            res = (y - y_hat).reshape(state["residual"].shape)
         return payload, {"residual": res, "inner": ist}
 
 
@@ -264,16 +291,18 @@ class MomentumCorrection(_Wrapper):
         return jnp.where(jnp.abs(v) >= thr, v, 0.0)
 
     def encode(self, state, rng, x):
-        u = self.momentum * state["u"].reshape(x.shape) + x
-        v = state["v"].reshape(x.shape) + u
-        v_enc = v
-        if self.warmup_rounds:
-            v_enc = self._anneal_mask(v, state["round"])
-        payload, ist = self.inner.encode(state["inner"], rng, v_enc)
-        v_hat = self.inner.decode(payload, v.shape[0])
-        sent = v_hat != 0.0
-        new_v = (v - v_hat).reshape(state["v"].shape)
-        new_u = jnp.where(sent, 0.0, u).reshape(state["u"].shape)
+        with jax.named_scope(scopes.STAGE + "dgc"):
+            u = self.momentum * state["u"].reshape(x.shape) + x
+            v = state["v"].reshape(x.shape) + u
+            v_enc = v
+            if self.warmup_rounds:
+                v_enc = self._anneal_mask(v, state["round"])
+            payload, ist = scoped_encode(self.inner, state["inner"], rng,
+                                         v_enc)
+            v_hat = scoped_decode(self.inner, payload, v.shape[0])
+            sent = v_hat != 0.0
+            new_v = (v - v_hat).reshape(state["v"].shape)
+            new_u = jnp.where(sent, 0.0, u).reshape(state["u"].shape)
         new_state = {"u": new_u, "v": new_v, "inner": ist}
         if self.warmup_rounds:
             new_state["round"] = state["round"] + 1

@@ -64,6 +64,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compress.api import CommTransform, Payload, PyTree
+from repro.compress.pipeline import scoped_decode, scoped_encode
+from repro.obs import scopes
 
 __all__ = ["SecAgg", "DPNoise", "PRIVACY_STAGES", "make_privacy_stage",
            "has_mask_ctx", "inject_mask_ctx", "drop_mask_ctx", "ring_mask",
@@ -278,20 +280,22 @@ class SecAgg(CommTransform):
                 "inner": self.inner.init(shape)}
 
     def encode(self, state, rng, x):
-        # the inner pipeline sees the rng stream unmodified — masked and
-        # unmasked runs draw identical quantization randomness
-        payload, ist = self.inner.encode(state["inner"], rng, x)
-        key, idx, coh = (state["mask_key"], state["mask_idx"],
-                         state["mask_cohort"])
-        out = dict(mask_payload(payload, key, idx, coh, +1))
+        with jax.named_scope(scopes.STAGE + "secagg"):
+            # the inner pipeline sees the rng stream unmodified — masked
+            # and unmasked runs draw identical quantization randomness
+            payload, ist = scoped_encode(self.inner, state["inner"], rng, x)
+            key, idx, coh = (state["mask_key"], state["mask_idx"],
+                             state["mask_cohort"])
+            out = dict(mask_payload(payload, key, idx, coh, +1))
         out["secagg_ctx"] = {"key": key, "idx": idx, "cohort": coh}
         return out, dict(state, inner=ist)
 
     def decode(self, payload: Payload, n: int):
-        p = dict(payload)
-        ctx = p.pop("secagg_ctx")
-        body = mask_payload(p, ctx["key"], ctx["idx"], ctx["cohort"], -1)
-        return self.inner.decode(body, n)
+        with jax.named_scope(scopes.STAGE + "secagg"):
+            p = dict(payload)
+            ctx = p.pop("secagg_ctx")
+            body = mask_payload(p, ctx["key"], ctx["idx"], ctx["cohort"], -1)
+            return scoped_decode(self.inner, body, n)
 
     # --- byte accounting: ctx is the out-of-band key channel, unbilled ----
     def meta_bits(self, n):
@@ -361,23 +365,25 @@ class DPNoise(CommTransform):
         return self.inner.init(shape)
 
     def encode(self, state, rng, x):
-        y = x
-        if math.isfinite(self.clip):
-            # this leaf's equal share of the joint L2 budget: clipping each
-            # of L leaves to clip/sqrt(L) bounds the whole update to clip
-            leaf_clip = self.clip / math.sqrt(self.n_leaves)
-            nrm = jnp.linalg.norm(y)
-            y = y * jnp.minimum(1.0, leaf_clip / jnp.maximum(nrm, 1e-12))
-        if self.sigma > 0.0:
-            # std is sigma x the JOINT sensitivity (clip, not leaf_clip):
-            # the L-leaf release is one Gaussian mechanism at rho=0.5/sigma^2
-            z = jax.random.normal(jax.random.fold_in(rng, DP_TAG),
-                                  y.shape, y.dtype)
-            y = y + jnp.asarray(self.sigma * self.clip, y.dtype) * z
-        return self.inner.encode(state, rng, y)
+        with jax.named_scope(scopes.STAGE + "dpnoise"):
+            y = x
+            if math.isfinite(self.clip):
+                # this leaf's equal share of the joint L2 budget: clipping
+                # each of L leaves to clip/sqrt(L) bounds the update to clip
+                leaf_clip = self.clip / math.sqrt(self.n_leaves)
+                nrm = jnp.linalg.norm(y)
+                y = y * jnp.minimum(1.0, leaf_clip / jnp.maximum(nrm, 1e-12))
+            if self.sigma > 0.0:
+                # std is sigma x the JOINT sensitivity (clip, not
+                # leaf_clip): the L-leaf release is one Gaussian mechanism
+                # at rho=0.5/sigma^2
+                z = jax.random.normal(jax.random.fold_in(rng, DP_TAG),
+                                      y.shape, y.dtype)
+                y = y + jnp.asarray(self.sigma * self.clip, y.dtype) * z
+            return scoped_encode(self.inner, state, rng, y)
 
     def decode(self, payload, n):
-        return self.inner.decode(payload, n)
+        return scoped_decode(self.inner, payload, n)
 
     def meta_bits(self, n):
         return self.inner.wire_bits(n)

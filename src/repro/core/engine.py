@@ -43,7 +43,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compress.api import Identity, make_compressor
-from repro.compress.pipeline import error_feedback, momentum_correction
+from repro.compress.pipeline import (error_feedback, momentum_correction,
+                                     scoped_decode, scoped_encode)
 from repro.compress.secure_agg import (DPNoise, MASK_TAG, SecAgg,
                                        bind_n_leaves, has_mask_ctx,
                                        inject_mask_ctx)
@@ -54,6 +55,7 @@ from repro.core.types import CommLedger, FLConfig, FLState
 from repro.data.pipeline import capability_latency
 from repro.models import sharding as shd
 from repro.models.model import Model
+from repro.obs import scopes
 from repro.obs import telemetry as obs_tel
 
 PyTree = Any
@@ -240,16 +242,19 @@ class RoundProgram:
     """One FL round as an ordered sequence of named hops.
 
     Each hop is ``fn(ctx) -> ctx`` over a plain dict context; the program is
-    traced once under jit so hop granularity costs nothing at runtime. The
-    final hop must leave ``ctx["new_state"]`` / ``ctx["metrics"]``."""
+    traced once under jit so hop granularity costs nothing at runtime. Each
+    hop runs under ``jax.named_scope("hop.<name>")`` (HLO metadata only), so
+    a device trace attributes its operations to hops (``repro.obs.scopes``).
+    The final hop must leave ``ctx["new_state"]`` / ``ctx["metrics"]``."""
 
     topology: Topology
     hops: tuple                        # ((name, fn), ...)
 
     def __call__(self, state: FLState, batch) -> tuple:
         ctx = {"state": state, "batch": batch}
-        for _name, fn in self.hops:
-            ctx = fn(ctx)
+        for name, fn in self.hops:
+            with jax.named_scope(scopes.HOP + name):
+                ctx = fn(ctx)
         return ctx["new_state"], ctx["metrics"]
 
     @property
@@ -604,21 +609,21 @@ def make_dispatch(model: Model, fl: FLConfig, up, down, C: int,
 
                     def one(x, r, st, i, mkey=mkey):
                         st = inject_mask_ctx(st, mkey, i, C)
-                        payload, nst = up.encode(st, r, x)
-                        return up.decode(payload, x.shape[0]), nst
+                        payload, nst = scoped_encode(up, st, r, x)
+                        return scoped_decode(up, payload, x.shape[0]), nst
                     dec, nst = jax.vmap(one)(
                         flat, rs, comm_state[li],
                         jnp.arange(C, dtype=jnp.int32))
                 else:
                     def one(x, r, st):
-                        payload, nst = up.encode(st, r, x)
-                        return up.decode(payload, x.shape[0]), nst
+                        payload, nst = scoped_encode(up, st, r, x)
+                        return scoped_decode(up, payload, x.shape[0]), nst
                     dec, nst = jax.vmap(one)(flat, rs, comm_state[li])
                 st_rows.append(nst)
             else:
                 def one(x, r):
-                    payload, _ = up.encode(up.init(x.shape), r, x)
-                    return up.decode(payload, x.shape[0])
+                    payload, _ = scoped_encode(up, up.init(x.shape), r, x)
+                    return scoped_decode(up, payload, x.shape[0])
                 dec = jax.vmap(one)(flat, rs)
             dec_rows.append(dec.reshape((C,) + shape))
         dec_tree = jax.tree.unflatten(jax.tree.structure(deltas), dec_rows)
@@ -630,10 +635,11 @@ def make_dispatch(model: Model, fl: FLConfig, up, down, C: int,
         # committed by earlier events; the barrier makes the weighted mean
         # lower identically in both programs (bit-exact degenerate
         # equivalence, DESIGN.md §7)
-        rows = jax.lax.optimization_barrier(rows)
-        return jax.tree.map(
-            lambda leaf: ((w_num[:, None] * leaf.reshape(C, -1)).sum(0)
-                          / wsum).reshape(leaf.shape[1:]), rows)
+        with jax.named_scope(scopes.STAGE + "aggregate"):
+            rows = jax.lax.optimization_barrier(rows)
+            return jax.tree.map(
+                lambda leaf: ((w_num[:, None] * leaf.reshape(C, -1)).sum(0)
+                              / wsum).reshape(leaf.shape[1:]), rows)
 
     return Dispatch(downlink=downlink, local_update=local_update,
                     wire_rows=wire_rows, aggregate_rows=aggregate_rows,
@@ -1813,9 +1819,12 @@ class RoundRunner:
         round_fn = engine.round_fn
 
         def body(state, _):
-            batch = data_fn(state.round)
+            with jax.named_scope(scopes.HOP + "data"):
+                batch = data_fn(state.round)
             new_state, metrics = round_fn(state, batch)
-            if metrics_fn is not None:
+            if metrics_fn is None:
+                return new_state, metrics
+            with jax.named_scope(scopes.HOP + "eval"):
                 if ee == 1:
                     metrics = metrics_fn(new_state, metrics)
                 else:
@@ -1849,7 +1858,9 @@ class RoundRunner:
     def run(self, state, n: int):
         """Run ``n`` rounds; returns (state, metrics) with every metric (and
         the per-round CommLedger) stacked over a leading (n,) round dim.
-        ``n <= 0`` is a no-op returning ``(state, None)``."""
+        ``n <= 0`` is a no-op returning ``(state, None)``.  Each chunk call
+        runs under a ``repro.chunk`` profiler annotation, which puts it on
+        the device trace's timeline."""
         if n <= 0:
             return state, None
         shardings = getattr(self.engine, "state_shardings", None)
@@ -1869,7 +1880,9 @@ class RoundRunner:
         while done < n:
             k = min(self.chunk, n - done)
             if self.tracer is None:
-                state, m = self._jit(state, k)
+                # the Tracer's span opens the same annotation itself
+                with jax.profiler.TraceAnnotation(scopes.ANNOTATION + "chunk"):
+                    state, m = self._jit(state, k)
             else:
                 # span kind "compile" when this chunk shape triggered a fresh
                 # compilation (jit compiles lazily, so the span necessarily

@@ -10,12 +10,16 @@
     opt-in ``jax.profiler`` hook around ``run_rounds`` chunks;
   * :mod:`repro.obs.report` — ``python -m repro.obs.report run.jsonl``:
     terminal / markdown run summary (byte waterfall, staleness histogram,
-    time breakdown, claims-ready rows).
+    time breakdown, claims-ready rows);
+  * :mod:`repro.obs.scopes` — the naming contract between the round
+    program and a device trace (``hop.*`` / ``stage.*`` named scopes,
+    ``repro.*`` profiler annotations) and ``scope_table``, which reads it
+    back out of a compiled HLO text.
 
-The package import is lazy on purpose: ``trace`` and ``report`` are
-stdlib-only (jax loads only inside the helpers that need it), so the report
-CLI runs anywhere the JSONL file does — importing :mod:`repro.obs` must not
-drag jax in.
+The package import is lazy on purpose: ``trace``, ``report`` and
+``scopes`` are stdlib-only (jax loads only inside the helpers that need
+it), so the report CLI runs anywhere the JSONL file does — importing
+:mod:`repro.obs` must not drag jax in.
 """
 _LAZY = {
     "RoundStats": "telemetry", "TelemetrySpec": "telemetry",
@@ -25,6 +29,7 @@ _LAZY = {
     "N_STALENESS_BUCKETS": "telemetry",
     "Tracer": "trace", "SCHEMA_VERSION": "trace",
     "validate_file": "trace", "validate_record": "trace",
+    "scope_table": "scopes",
 }
 
 __all__ = sorted(_LAZY)

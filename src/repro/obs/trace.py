@@ -23,7 +23,10 @@ from __future__ import annotations
 
 import contextlib
 import json
+import sys
 import time
+
+from repro.obs.scopes import ANNOTATION
 
 SCHEMA_VERSION = 1
 
@@ -65,11 +68,17 @@ class Tracer:
     def span(self, kind: str, **attrs):
         """Timed section; yields a mutable attrs dict so the body can
         retag itself (e.g. a chunk span upgrading to ``compile`` once the
-        jit cache says this shape compiled here)."""
+        jit cache says this shape compiled here).  Where jax is already
+        loaded, the span also opens a ``repro.<kind>`` profiler annotation,
+        so a ``--profile-dir`` trace shows it on the device's timeline."""
         rec = dict(kind=kind, **attrs)
+        jax = sys.modules.get("jax")
+        note = (jax.profiler.TraceAnnotation(ANNOTATION + kind)
+                if jax is not None else contextlib.nullcontext())
         ts, t0 = time.time(), time.perf_counter()
         try:
-            yield rec
+            with note:
+                yield rec
         finally:
             self._write(dict(type="span", ts=ts,
                              dur_s=time.perf_counter() - t0, **rec))

@@ -49,8 +49,14 @@ def _data_fn(r):
     return sample_round(DATA, jax.random.fold_in(jax.random.PRNGKey(1), r))
 
 
+# (spec, telemetry) -> (RoundRunner, first state) of a plain sim(4) run:
+# its compiled round is read again by the scope test at no compile cost
+_SIM4 = {}
+
+
 def _run(spec, topo_fn, pop=None, n=3, telemetry=False, data_fn=None,
          **fl_kw):
+    from repro.core.engine import RoundRunner
     from repro.models.model import Model
     model = Model(CFG)
     fl = FLConfig(algorithm="fedavg", local_steps=1, local_lr=0.2,
@@ -58,8 +64,12 @@ def _run(spec, topo_fn, pop=None, n=3, telemetry=False, data_fn=None,
     dfn = data_fn or _data_fn
     e = make_round_engine(model, fl, topo_fn(), chunk=32, data_fn=dfn,
                           population=pop)
-    st = e.init_fn(jax.random.PRNGKey(0))
-    st, ms = run_rounds(e, st, dfn, n, chunk=1, donate=False)
+    st0 = e.init_fn(jax.random.PRNGKey(0))
+    runner = RoundRunner(e, dfn, chunk=1, donate=False)
+    st, ms = runner.run(st0, n)
+    if (pop is None and data_fn is None and not fl_kw
+            and e.topology == Topology.sim(4)):
+        _SIM4[(spec, telemetry)] = (runner, st0)
     return e, st, ms
 
 
@@ -393,3 +403,60 @@ def test_star_runner_single_compile_per_chunk_shape():
     st, _ = runner.run(st, 4)  # two chunks of the same shape
     n = runner.cache_size()
     assert n == 1, f"star runner recompiled: {n} executables for one shape"
+
+
+# ---------------------------------------------------------------------------
+# named scopes reach the compiled round (repro.obs.scopes)
+# ---------------------------------------------------------------------------
+
+# what a scan does to carry its state and stack its outputs
+PLUMBING = {"tuple", "get-tuple-element", "dynamic-update-slice", "copy",
+            "parameter", "constant", "bitcast"}
+
+
+def _opcodes(hlo):
+    """``{instruction: opcode}``; a fusion reads as its fused root's."""
+    import re
+    comp, roots, ops = None, {}, {}
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$", line)
+        if m:
+            op = re.search(r"(?:^|\s)([a-z][\w\-]*)\(", m.group(3)).group(1)
+            called = re.search(r"\bcalls=%?([\w.\-]+)", m.group(3))
+            ops[m.group(2)] = (op, called.group(1) if called else None)
+            if m.group(1):
+                roots[comp] = op
+    return {n: (roots[c] if op == "fusion" and c in roots else op)
+            for n, (op, c) in ops.items()}
+
+
+@pytest.mark.parametrize("spec,stages", [
+    ("topk:0.25>>qsgd:8", {"topk", "qsgd", "ef"}),      # error feedback
+    ("qsgd:4>>secagg", {"qsgd", "secagg"}),
+])
+def test_scopes_name_the_compiled_round(spec, stages):
+    # the round the telemetry-off differential ran, when it ran first
+    import re
+    from repro.obs.scopes import scope_table, scopes_of
+    if (spec, False) not in _SIM4:
+        _run(spec, lambda: Topology.sim(4))
+    runner, state = _SIM4[(spec, False)]
+    hlo = runner._jit.lower(state, 1).compile().as_text()
+    table = scope_table(hlo)
+    hops = {hop for hop, _ in table.values()}
+    assert {"local_update", "wire", "data"} <= hops
+    found = {st for _, st in table.values()} - {None}
+    assert stages <= found, found
+    assert {hop for hop, st in table.values() if st} == {"wire"}
+    # the weighted mean may fuse into the server step; its own operations
+    # carry stage.aggregate under hop.wire all the same
+    named = [scopes_of(n) for n in re.findall(r'op_name="([^"]*)"', hlo)]
+    assert ("wire", "aggregate") in named
+    assert {hop for hop, st in named if st} == {"wire"}
+    ops = _opcodes(hlo)
+    loose = {n: ops[n] for n, (hop, _) in table.items() if hop is None}
+    assert set(loose.values()) <= PLUMBING, loose
