@@ -17,9 +17,18 @@
 
 All operate on flattened f32 leaves; k is a static fraction of n (fixed shapes
 under jit — matching the source papers' fixed-sparsity setting).
+
+``topk`` selects without sorting the leaf (``_topk_select``): the k-th
+magnitude by a search over the float's bit pattern, the survivors by a
+descent through per-row kept counts, then one sort of the k survivors —
+bit-identical to ``lax.top_k`` (DESIGN.md §6).  ``SBC.encode``,
+``RandMask._idx``, ``FusedSTC`` (``ops._stc_threshold``),
+``MomentumCorrection._anneal_mask``, ``sketch`` and ``core/selection.py``
+keep ``lax.top_k``: no benchmarked wire runs them.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -32,12 +41,195 @@ def _k(n, fraction):
     return max(1, int(round(n * fraction)))
 
 
+SORT_BELOW = 1 << 13    # shorter leaves take lax.top_k on the TPU
+LANES = 128             # row width of the kept-count tree
+WAYS = 16               # the threshold search splits its interval 16 ways
+CHUNK = 1 << 16         # survivors located per step of the descent
+
+
+def _mag_bits(x):
+    """|x| as int32 bits: for non-NaN floats they order as the magnitude."""
+    return jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32)
+
+
+def _kth_magnitude(xr, n, k):
+    """(t, count(bits > t)): t is the bit pattern of the k-th largest |x|,
+    x the n entries of the row view ``xr``.
+
+    Keeps count(bits >= lo) >= k > count(bits >= hi) over [0, +inf + 1) and
+    cuts the interval at ``WAYS - 1`` points per read of x (one Pallas
+    pass, ``ops.count_at_least``), until hi = lo + 1: 8 reads."""
+    from repro.kernels import ops
+    cuts = jnp.arange(1, WAYS, dtype=jnp.int32)
+    reads, d = 0, 0x7F800001
+    while d > 1:
+        reads, d = reads + 1, -(-d // WAYS)
+
+    def step(_, c):
+        lo, hi, n_hi = c
+        d = hi - lo
+        cand = lo + (d // WAYS) * cuts + (d % WAYS) * cuts // WAYS
+        cnt = ops.count_at_least(xr, cand, n)
+        ok = cnt >= k
+        above = jnp.where(ok, jnp.int32(0x7FFFFFFF), cand)
+        first = jnp.argmin(above)
+        hit = above[first] < hi
+        return (jnp.max(jnp.where(ok, cand, lo)),
+                jnp.where(hit, cand[first], hi),
+                jnp.where(hit, cnt[first], n_hi))
+
+    t, _, n_gt = jax.lax.fori_loop(
+        0, reads, step, (jnp.int32(0), jnp.int32(0x7F800001), jnp.int32(0)))
+    return t, n_gt
+
+
+def _rows(v):
+    """(ceil(n / LANES), LANES) view of a 1-D vector, zero-padded."""
+    m = -(-v.shape[0] // LANES) * LANES
+    return jnp.pad(v, (0, m - v.shape[0])).reshape(-1, LANES)
+
+
+def _row_cumsum(v, bound):
+    """Inclusive prefix sum along the last axis (LANES) of int32 entries in
+    [0, bound], exact: each 8-bit digit times the upper-triangular ones on
+    the MXU (bf16 holds a digit exactly, f32 sums of 128 digits too)."""
+    tri = jnp.triu(jnp.ones((LANES, LANES), jnp.bfloat16))
+    out, shift = 0, 0
+    while True:
+        digit = v if bound < 256 else (v >> shift) & 255
+        part = jnp.dot(digit.astype(jnp.bfloat16), tri,
+                       preferred_element_type=jnp.float32)
+        out = out + (part.astype(jnp.int32) << shift)
+        shift += 8
+        if bound >> shift == 0:
+            return out
+
+
+def _cumsum(v, bound):
+    """Inclusive prefix sum of a 1-D int32 vector with entries in
+    [0, bound], a row of LANES at a time."""
+    rows = _row_cumsum(_rows(v), bound)
+    if rows.shape[0] > 1:
+        totals = rows[:, -1]
+        rows = rows + (_cumsum(totals, bound * LANES) - totals)[:, None]
+    return rows.reshape(-1)[:v.shape[0]]
+
+
+def _pick(row, bound, before, j):
+    """Per target j: the lane of a row of kept counts (each at most
+    ``bound``) that holds the j-th kept entry, and the kept count before
+    that lane."""
+    incl = before[:, None] + _row_cumsum(row, bound)
+    lane = jnp.sum(incl < j[:, None], axis=1, dtype=jnp.int32)
+    left = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) < lane[:, None]
+    return lane, before + jnp.sum(jnp.where(left, row, 0), axis=1,
+                                  dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _topk_select(x, k):
+    """``(x[idx], idx)`` for the k largest |x|, largest first, ties by
+    ascending index: bit-identical to ``lax.top_k(jnp.abs(x), k)``'s
+    indices, with no sort over the n entries (x has no NaN).
+
+    1. t = the k-th largest |x| (``_kth_magnitude``). Kept: every entry
+       above t and the first ``k - count(> t)`` entries equal to t, those
+       at or before the position ``cut`` of the last one.
+    2. One read of x counts the kept entries of each row of LANES; sums of
+       LANES counts build the levels above until one row remains.
+    3. The j-th kept entry is found by descending the levels, one gathered
+       row per level, ``CHUNK`` targets at a time: the k indices come out
+       in ascending order.
+    4. One stable sort of the k survivors by descending magnitude.
+
+    Nothing n-long is kept but the rows of x that XLA may lay out for the
+    row reads; the per-row counts are n / LANES long."""
+    n = x.shape[0]
+    xr = _rows(x)
+    t, n_gt = _kth_magnitude(xr, n, k)
+    r = k - n_gt                                # ties at t to keep
+
+    def bits_pos(rows, row_id):
+        pos = row_id[:, None] * LANES + jax.lax.broadcasted_iota(
+            jnp.int32, rows.shape, 1)
+        return _mag_bits(rows), pos
+
+    bits, pos = bits_pos(xr, jnp.arange(xr.shape[0], dtype=jnp.int32))
+    ties = jnp.sum((bits == t) & (pos < n), axis=1, dtype=jnp.int32)
+    ties_before = _cumsum(ties, LANES) - ties
+    levels = [jnp.sum(bits > t, axis=1, dtype=jnp.int32)
+              + jnp.clip(r - ties_before, 0, ties)]
+    # the position of the r-th tie: the last one kept
+    last = jnp.sum(ties_before + ties < r, dtype=jnp.int32)
+    bits, pos = bits_pos(jax.lax.dynamic_slice_in_dim(xr, last, 1),
+                         last[None])
+    tie_rank = ties_before[last] + _row_cumsum(
+        ((bits == t) & (pos < n)).astype(jnp.int32), 1)
+    cut = last * LANES + jnp.sum(tie_rank < r, dtype=jnp.int32)
+    while levels[-1].shape[0] > LANES:
+        levels.append(jnp.sum(_rows(levels[-1]), axis=1, dtype=jnp.int32))
+    top = levels[-1]
+    top_incl = _cumsum(top, min(n, LANES ** len(levels)))
+
+    kc = min(k, CHUNK)
+    steps = -(-k // kc)
+
+    def descend(c, out):
+        j = jnp.minimum(c * kc + jnp.arange(1, kc + 1, dtype=jnp.int32), k)
+        left = top_incl[None, :] < j[:, None]
+        node = jnp.sum(left, axis=1, dtype=jnp.int32)
+        before = jnp.sum(jnp.where(left, top[None, :], 0), axis=1,
+                         dtype=jnp.int32)
+        for depth in range(len(levels) - 1, 0, -1):
+            lane, before = _pick(_rows(levels[depth - 1])[node],
+                                 min(n, LANES ** depth), before, j)
+            node = node * LANES + lane
+        rows = xr[node]
+        bits, pos = bits_pos(rows, node)
+        kept = (bits > t) | ((bits == t) & (pos <= cut))
+        lane, _ = _pick(kept.astype(jnp.int32), 1, before, j)
+        # the one lane's bits, by a max: exact for -0.0, unlike a sum
+        lanes = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+        val = jax.lax.bitcast_convert_type(jnp.max(jnp.where(
+            lanes == lane[:, None],
+            jax.lax.bitcast_convert_type(rows, jnp.int32),
+            jnp.iinfo(jnp.int32).min), axis=1), x.dtype)
+        return (jax.lax.dynamic_update_slice(out[0], node * LANES + lane,
+                                             (c * kc,)),
+                jax.lax.dynamic_update_slice(out[1], val, (c * kc,)))
+
+    out = (jnp.zeros((steps * kc,), jnp.int32),
+           jnp.zeros((steps * kc,), x.dtype))
+    idx, vals = (descend(0, out) if steps == 1 else
+                 jax.lax.fori_loop(0, steps, descend, out))
+    _, idx, vals = jax.lax.sort((-_mag_bits(vals[:k]), idx[:k], vals[:k]),
+                                num_keys=1, is_stable=True)
+    return vals, idx
+
+
+def _topk(x, k):
+    """``(x[idx], idx)`` for the k largest |x|, as ``lax.top_k`` orders
+    them.  On the TPU ``lax.top_k`` sorts the whole leaf, so a leaf of
+    ``SORT_BELOW`` entries or more takes ``_topk_select``; a shorter leaf,
+    and every leaf where the Pallas kernels are interpreted (the CPU, whose
+    top-k is a partial sort), is faster through ``lax.top_k`` (PERF.md §6).
+    Both give the same bits."""
+    from repro.kernels import ops
+    if (x.shape[0] >= SORT_BELOW and jax.default_backend() in ("cpu", "tpu")
+            and not ops._interpret()):
+        return _topk_select(x, k)
+    idx = jax.lax.top_k(jnp.abs(x), k)[1]
+    return x[idx], idx
+
+
 class TopK(CommTransform):
-    """``backend="kernel"``: the dense masking pass runs through the fused
-    ``threshold_sparsify`` Pallas kernel; index *extraction* stays in XLA
-    (``lax.top_k`` — TPUs have no in-kernel compaction, DESIGN.md §6).
-    ``top_k`` breaks magnitude ties by ascending index on both the raw and
-    the masked vector, so the kernel path is bit-exact against pure JAX."""
+    """Magnitude top-k; index extraction is ``_topk_select`` on both
+    backends (threshold search, per-row kept counts, a sort of the k
+    survivors), bit-identical to ``lax.top_k`` with its ties by ascending
+    index.  ``backend="kernel"``: the dense masking pass runs through the
+    fused ``threshold_sparsify`` Pallas kernel at the k-th magnitude and the
+    payload values are gathered from its output, ``kept[idx] == x[idx]``
+    bit for bit (DESIGN.md §6)."""
     biased = True
     carrier_key = "vals"
     kernel_capable = True
@@ -52,17 +244,18 @@ class TopK(CommTransform):
     def encode(self, state, rng, x):
         n = x.shape[0]
         k = _k(n, self.fraction)
-        vals, idx = jax.lax.top_k(jnp.abs(x), k)
+        vals, idx = _topk(x, k)
         if self.backend == "kernel":
             from repro.kernels import ops
-            # ONE top_k for threshold + indices; the fused kernel pass
-            # produces the dense masked vector the payload values are
-            # gathered from (kept[idx] == x[idx] bit-exactly, ties
-            # included). The pass also emits the EF residual — wiring it
-            # into the ErrorFeedback wrapper is the roadmap's TPU HBM win.
-            kept, _ = ops.threshold_sparsify(x, vals[-1], self.block)
-            return {"vals": kept[idx], "idx": idx.astype(jnp.int32)}, state
-        return {"vals": x[idx], "idx": idx.astype(jnp.int32)}, state
+            # the fused kernel pass at the k-th magnitude produces the
+            # dense masked vector the payload values are gathered from
+            # (kept[idx] == x[idx] bit-exactly, ties included). The pass
+            # also emits the EF residual — wiring it into the
+            # ErrorFeedback wrapper is the roadmap's TPU HBM win.
+            kept, _ = ops.threshold_sparsify(x, jnp.abs(vals[-1]),
+                                             self.block)
+            return {"vals": kept[idx], "idx": idx}, state
+        return {"vals": vals, "idx": idx}, state
 
     def decode(self, payload, n):
         out = jnp.zeros((n,), jnp.float32)

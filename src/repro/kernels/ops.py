@@ -188,6 +188,17 @@ def threshold_sparsify(x, thresh, block=2048):
     return kept.reshape(-1)[:n], resid.reshape(-1)[:n]
 
 
+def count_at_least(xr, cand, n):
+    """Counts of |x| bit patterns at or above each candidate, in one read:
+    xr is the (R, 128) row view of a flat vector of n entries, cand int32
+    (m,) with m <= 16."""
+    m = cand.shape[0]
+    cand = jnp.pad(cand, (0, _topk.CANDIDATES - m),
+                   constant_values=jnp.iinfo(jnp.int32).max)
+    return _topk.count_at_least_rows(xr, cand.reshape(1, -1), n,
+                                     interpret=_interpret())[:m]
+
+
 def sketch(x, rows=5, cols=4096, seed=17):
     """Count-sketch via the one-hot-MXU kernel. Flat f32 (n,) -> (rows, cols)."""
     from repro.compress.sketch import hash_params

@@ -11,8 +11,8 @@ Parity classes (DESIGN.md §6):
   * ``exact=True``  — the kernel is deterministic and the blocked layout
     does not reorder any reduction: decoded payloads, comm_state, and
     ledger bytes must match BIT-EXACTLY (qsgd: shared uniforms sampled in
-    the pure blocked layout; topk: lax.top_k tie order preserved through
-    the masking pass).
+    the pure blocked layout; topk: the ``lax.top_k`` tie order, ascending
+    index, preserved through the masking pass).
   * ``exact=False`` — padding/blocking reorders a reduction (ternary's mu
     partial sums, count-sketch's per-chunk matmul accumulation): decoded
     payloads and state match within ``tol`` (relative, against the input
@@ -47,7 +47,13 @@ def heavy_hitters(seed, n):
     return x.at[idx].set(spikes)
 
 
-INPUTS = {"gaussian": gaussian, "hh": heavy_hitters}
+def ties(seed, n):
+    """Gaussian values rounded to a few levels: whole groups of entries
+    share the k-th magnitude, so the top-k cut falls inside a tie."""
+    return jnp.round(gaussian(seed, n) * 2.0) / 2.0
+
+
+INPUTS = {"gaussian": gaussian, "hh": heavy_hitters, "ties": ties}
 
 
 def case(name, spec, *, exact=True, tol=0.0, input="gaussian",
@@ -60,6 +66,7 @@ def case(name, spec, *, exact=True, tol=0.0, input="gaussian",
 # --- every kernel-capable stage, standalone --------------------------------
 STAGE_CASES = [
     case("topk", "topk:0.05"),
+    case("topk_ties", "topk:0.05", input="ties"),
     case("qsgd8", "qsgd:8"),
     case("qsgd4", "qsgd:4"),
     case("qsgd_block256", "qsgd:8,256"),

@@ -5,6 +5,8 @@ fuzzed test degrades to a fixed-seed parametrized sweep so the core
 round-trip/error-bound assertions still run (the CI image pins hypothesis,
 minimal images may not have it).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -163,3 +165,55 @@ def test_randmask_deterministic_given_seed(k):
     y1 = comp.decompress(p1, 256)
     y2 = comp.decompress(p1, 256)
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
+
+
+def _gauss2(n, seed=0):
+    return jax.random.normal(jax.random.PRNGKey(seed), (2, n))
+
+
+def _half_zero(n):
+    x = _gauss2(n).at[:, ::2].set(0.0).at[0, 1::4].set(-0.0)
+    few = jnp.where(jnp.arange(x[:, 1::2].shape[1]) < 10, 1.0, 0.0)
+    return x.at[:, 1::2].set(few)        # 10 non-zeros per row, k > 10
+
+
+# (input, k, descent chunk): ties at the threshold, ties at zero, signed
+# zeros and infinities, both ends of k, n off the 128-lane rows, and two
+# count levels walked in several chunks
+N = 3001
+SELECT_CASES = {
+    "gaussian": (lambda: _gauss2(N), 40, None),
+    "quantized": (lambda: jnp.round(_gauss2(N) * 2.0) / 2.0, 40, None),
+    "half_zero": (lambda: _half_zero(N), 40, None),
+    "zero_inf": (lambda: _gauss2(N).at[0, 5].set(jnp.inf)
+                 .at[1, 7].set(-jnp.inf).at[:, 9].set(-0.0)
+                 .at[:, 11].set(0.0), 40, None),
+    "k1": (lambda: _gauss2(N), 1, None),
+    "kn": (lambda: jnp.round(_gauss2(N)), N, None),
+    "levels_chunks": (lambda: jnp.round(_gauss2(20_001) * 4.0) / 4.0,
+                      1000, 256),
+}
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _select(x, k):
+    from repro.compress.sparsification import _topk_select
+    return jax.vmap(lambda v: _topk_select(v, k))(x)
+
+
+@pytest.mark.parametrize("case", list(SELECT_CASES))
+def test_topk_select_bit_identical_to_lax_top_k(case, monkeypatch):
+    """The sort-free selection returns ``lax.top_k``'s indices in its order
+    (descending magnitude, ties by ascending index) and x at them, bit for
+    bit, vmapped over two clients as the engine calls it."""
+    from repro.compress import sparsification
+    make, k, chunk = SELECT_CASES[case]
+    if chunk:
+        monkeypatch.setattr(sparsification, "CHUNK", chunk)
+    x = make()
+    vals, idx = _select(x, k)
+    want = jax.vmap(lambda v: jax.lax.top_k(jnp.abs(v), k)[1])(x)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(vals).view(np.int32),
+        np.asarray(jnp.take_along_axis(x, want, axis=1)).view(np.int32))

@@ -90,6 +90,10 @@ def _case(kernel, n):
     if kernel == "threshold_sparsify":
         return (topk_mask.threshold_sparsify_blocked,
                 [(_blocked_shape(n), f32), ((), f32)])
+    if kernel == "count_at_least":
+        return (lambda xr, c: topk_mask.count_at_least_rows(xr, c, n=n),
+                [((-(-n // topk_mask.LANES), topk_mask.LANES), f32),
+                 ((1, topk_mask.CANDIDATES), jnp.int32)])
     if kernel == "pack_codes":
         return (lambda c: bitpack.pack_codes_blocked(c, bits=2),
                 [(_blocked_shape(n), jnp.int8)])
@@ -109,8 +113,8 @@ def _case(kernel, n):
 
 
 KERNELS = ("qsgd_quantize", "qsgd_pack", "ternarize", "ternarize_pack",
-           "threshold_sparsify", "pack_codes", "unpack_codes",
-           "count_sketch")
+           "threshold_sparsify", "count_at_least", "pack_codes",
+           "unpack_codes", "count_sketch")
 
 
 @pytest.mark.parametrize("size", ["small", "full"])
@@ -126,3 +130,30 @@ def test_kernel_compiles_for_v5e(kernel, size, one_chip, full_n):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (kernel, size)
+
+
+SELECT_N = 48 * 1024         # the ln leaf of mamba2_370m, above SORT_BELOW
+
+
+@pytest.mark.parametrize("backend", ["jax", "kernel"])
+def test_topk_encode_sorts_only_the_survivors(backend, one_chip, monkeypatch):
+    """``TopK.encode`` compiled for a v5e, vmapped over 2 clients: the only
+    sort left is over the k survivors, none over the n-long leaf, and the
+    selection's temporaries stay within two n-long 4-byte buffers per
+    client (``lax.top_k`` held two) plus a small fixed slack."""
+    import re
+    from repro.compress.sparsification import TopK, _k
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n, clients, slack = SELECT_N, 2, 1 << 20
+    topk = TopK(0.01, backend=backend)
+    fn = jax.vmap(lambda x: topk.encode((), None, x)[0])
+    x = jax.ShapeDtypeStruct((clients, n), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(x).compile()
+    sorts = [line for line in compiled.as_text().splitlines()
+             if re.search(r"\bsort\(", line)]
+    dims = {int(d) for line in sorts
+            for d in re.findall(r"\[(?:\d+,)*?(\d+)\]", line.split("sort(")[0])}
+    assert sorts and _k(n, 0.01) in dims and n not in dims, sorts
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 2 * clients * n * 4 + slack, temp
